@@ -66,7 +66,7 @@ import os
 import time
 from pathlib import Path
 
-from repro import hostdev
+from repro import compile_cache, hostdev
 
 # must happen before the first jax import: force N host-platform devices so
 # the sharded mode has something to shard over on CPU-only machines
@@ -1058,6 +1058,7 @@ def main(argv=None):
                          "fraction + a pre-filled mixed serving segment) "
                          "-> BENCH_engine_join.json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.max_batch is not None:
         global BATCHES
         BATCHES = tuple(b for b in BATCHES if b <= args.max_batch)

@@ -30,23 +30,6 @@ Array = jax.Array
 BIG = 3.4e38
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """jax.shard_map across jax versions (new API vs jax.experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
-def _pvary(x, axis):
-    """jax.lax.pvary appeared with the vma checker; older jax is a no-op."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    return x
-
-
 # ---------------------------------------------------------------------------
 # repository-sharded bound pass + top-k merge
 # ---------------------------------------------------------------------------
@@ -106,7 +89,7 @@ def sharded_topk_bounds(
 
     spec_b = P(axes)
     spec_bd = P(axes, None)
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), spec_bd, spec_b, spec_b),
@@ -150,13 +133,13 @@ def ring_hausdorff(
             dv_nxt = jax.lax.ppermute(dv_cur, axis, perm)
             return mins, d_nxt, dv_nxt
 
-        mins0 = _pvary(jnp.full((q_s.shape[0],), BIG, jnp.float32), axis)
+        mins0 = jax.lax.pvary(jnp.full((q_s.shape[0],), BIG, jnp.float32), axis)
         mins, _, _ = jax.lax.fori_loop(0, n_dev, hop, (mins0, d_s, dv_s))
         nn = jnp.sqrt(jnp.minimum(mins, BIG))
         local_h = jnp.max(jnp.where(qv_s, nn, -BIG))
         return jax.lax.pmax(local_h, axis)
 
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis, None), P(axis)),
@@ -194,8 +177,8 @@ def ring_nn_distance(
             dv_nxt = jax.lax.ppermute(dv_cur, axis, perm)
             return mins, args, d_nxt, dv_nxt
 
-        mins0 = _pvary(jnp.full((q_s.shape[0],), BIG, jnp.float32), axis)
-        args0 = _pvary(jnp.full((q_s.shape[0],), -1, jnp.int32), axis)
+        mins0 = jax.lax.pvary(jnp.full((q_s.shape[0],), BIG, jnp.float32), axis)
+        args0 = jax.lax.pvary(jnp.full((q_s.shape[0],), -1, jnp.int32), axis)
         mins, args, _, _ = jax.lax.fori_loop(
             0, n_dev, hop, (mins0, args0, d_s, dv_s)
         )
@@ -204,7 +187,7 @@ def ring_nn_distance(
         args = jnp.where(qv_s, args, -1)
         return dist, args
 
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis, None), P(axis)),
@@ -248,7 +231,7 @@ def sharded_topk_gbo(
         return top, gids[pos]
 
     spec = P(axes)
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axes, None), spec),

@@ -362,15 +362,19 @@ def topk_join_host(repo: Repository, pointsets, k: int, mode: str):
     d_val = np.asarray(repo.ds_index.valid)
     slot_valid = np.asarray(repo.ds_valid)
     S = d_pts.shape[0]
-    d_cells = [set(_host_cells(d_pts[s], d_val[s], lo, hi, theta_f).tolist())
-               if slot_valid[s] else set() for s in range(S)]
+    # one cell-id call for every slot: per-slot calls cost a device
+    # round trip each, which dominates at repository scale on a chip
+    cells = np.asarray(zorder.cell_ids(jnp.asarray(d_pts), lo, hi, theta_f))
+    d_cells = [set(cells[s][d_val[s]].tolist()) if slot_valid[s] else set()
+               for s in range(S)]
 
     vals = np.full((len(pointsets), k), -1, np.int32)
     ids = np.full((len(pointsets), k), -1, np.int32)
     for b, q in enumerate(pointsets):
         q = np.asarray(q, np.float32)
         qc = _host_cells(q, np.ones(len(q), bool), lo, hi, theta_f)
-        q_cells = set(qc.tolist())
+        qc = qc.tolist()
+        q_cells = set(qc)
         scores = np.full((S,), -1, np.int64)
         for s in range(S):
             if not slot_valid[s]:
@@ -378,7 +382,7 @@ def topk_join_host(repo: Repository, pointsets, k: int, mode: str):
             if mode == "overlap":
                 scores[s] = len(q_cells & d_cells[s])
             else:
-                scores[s] = sum(int(c) in d_cells[s] for c in qc.tolist())
+                scores[s] = sum(c in d_cells[s] for c in qc)
         top = np.argsort(-scores, kind="stable")[:k]
         t = len(top)
         vals[b, :t] = scores[top]

@@ -71,9 +71,7 @@ def signature(points: Array, valid: Array, lo: Array, hi: Array, theta: int) -> 
     w = num_words(theta)
     occ = occ.reshape(w, WORD_BITS)
     shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)
-    return jnp.bitwise_or.reduce(occ << shifts, axis=1) if hasattr(
-        jnp.bitwise_or, "reduce"
-    ) else (occ << shifts).sum(axis=1).astype(jnp.uint32)
+    return jnp.bitwise_or.reduce(occ << shifts, axis=1)
 
 
 def sig_union(a: Array, b: Array) -> Array:

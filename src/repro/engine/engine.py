@@ -327,8 +327,15 @@ class LocalDispatcher:
         self.repo = repo
         self.n_slots = repo.n_slots
 
-    def _bind(self, impl):
-        jitted = jax.jit(impl)
+    def _bind(self, impl, name: str):
+        def program(*args, **kw):
+            return impl(*args, **kw)
+
+        # the compiled program is named after its op (``jit_<name>`` in IR
+        # dumps and device traces); a bare partial would lower as
+        # ``jit__unknown``
+        program.__name__ = program.__qualname__ = name
+        jitted = jax.jit(program)
 
         def call(*args, **kw):
             return jitted(self.repo, *args, **kw)
@@ -336,17 +343,20 @@ class LocalDispatcher:
         return call
 
     def build_range_search(self):
-        return self._bind(batched_ops.range_search_batched)
+        return self._bind(batched_ops.range_search_batched, "range_search")
 
     def build_topk_ia(self, k: int):
-        return self._bind(partial(batched_ops.topk_ia_batched, k=k))
+        return self._bind(partial(batched_ops.topk_ia_batched, k=k),
+                          "topk_ia")
 
     def build_topk_gbo(self, k: int):
-        return self._bind(partial(batched_ops.topk_gbo_batched, k=k))
+        return self._bind(partial(batched_ops.topk_gbo_batched, k=k),
+                          "topk_gbo")
 
     def build_topk_hausdorff_approx(self, k: int):
         return self._bind(
-            partial(batched_ops.topk_hausdorff_approx_batched, k=k))
+            partial(batched_ops.topk_hausdorff_approx_batched, k=k),
+            "topk_hausdorff_approx")
 
     def build_topk_hausdorff(self, k: int, refine_levels: int, chunk: int):
         # batched end-to-end: (B, ...) query batch -> one device dispatch
@@ -360,18 +370,20 @@ class LocalDispatcher:
         return call
 
     def build_range_points(self):
-        return self._bind(batched_ops.range_points_batched)
+        return self._bind(batched_ops.range_points_batched, "range_points")
 
     def build_nnp(self):
-        return self._bind(batched_ops.nnp_pruned_batched)
+        return self._bind(batched_ops.nnp_pruned_batched, "nnp")
 
     def build_topk_overlap(self, k: int, chunk: int):
         return self._bind(partial(batched_ops.topk_join_batched, k=k,
-                                  mode="overlap", chunk=chunk))
+                                  mode="overlap", chunk=chunk),
+                          "topk_overlap")
 
     def build_topk_coverage(self, k: int, chunk: int):
         return self._bind(partial(batched_ops.topk_join_batched, k=k,
-                                  mode="coverage", chunk=chunk))
+                                  mode="coverage", chunk=chunk),
+                          "topk_coverage")
 
     def build_join_rerank(self, mode: str):
         # dataset→dataset pipeline stage 2: row-wise exact join score of
@@ -382,7 +394,7 @@ class LocalDispatcher:
             return join_search.pair_scores(repo, d_pts, d_val,
                                            q_pts, q_val, mode)
 
-        return self._bind(impl)
+        return self._bind(impl, f"join_rerank_{mode}")
 
 
 class QueryEngine:

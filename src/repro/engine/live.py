@@ -530,7 +530,6 @@ class LiveRepository:
             stage = jax.jit(scatter)
         else:
             from jax.sharding import PartitionSpec as P
-            from repro.core.distributed import _shard_map
             axis = disp.axis
 
             def local(repo_s, slots, rows, sigs, valids):
@@ -565,7 +564,7 @@ class LiveRepository:
                          gat(ds_sigs), gat(ds_valid))
                 return ds_index, ds_sigs, ds_valid, roots
 
-            stage = jax.jit(_shard_map(
+            stage = jax.jit(jax.shard_map(
                 local, mesh=disp.mesh,
                 in_specs=(specs, P(), P(), P(), P()),
                 out_specs=(specs.ds_index, specs.ds_sigs, specs.ds_valid,
@@ -640,7 +639,6 @@ class LiveRepository:
         B_pad = geom.n_slots
 
         from jax.sharding import PartitionSpec as P
-        from repro.core.distributed import _shard_map
 
         def local(repo_s):
             me = jax.lax.axis_index(axis)
@@ -662,7 +660,7 @@ class LiveRepository:
                      fs[:B_pad], fv[:B_pad])
             return jax.tree.map(loc, fi), loc(fs), loc(fv), roots
 
-        sm = jax.jit(_shard_map(
+        sm = jax.jit(jax.shard_map(
             local, mesh=disp.mesh, in_specs=(specs,),
             out_specs=(specs.ds_index, specs.ds_sigs, specs.ds_valid,
                        (P(), P(), P(), P(), P(), P())),

@@ -82,7 +82,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import geometry, join_search, point_search, search
-from repro.core.distributed import _shard_map
 from repro.core.repo_index import Repository
 from repro.engine import batched_ops, merge
 from repro.engine.engine import (DEFAULT_BUCKETS, DEFAULT_RESULT_CACHE,
@@ -234,8 +233,8 @@ class ShardedDispatcher:
         return P(self.row_axis)
 
     def _smap(self, fn, in_specs, out_specs):
-        sm = _shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
+        sm = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         if self.row_axis is None:
             return sm
         n_rep = int(self.mesh.shape[self.row_axis])

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.hausdorff import tile_sq_dists
+
 BIG = 3.4e38  # python float: baked into the kernel, not a captured const
 
 TQ = 256
@@ -19,6 +21,9 @@ TD = 512
 
 
 def _nn_kernel(q_ref, d_ref, dvalid_ref, dist_ref, idx_ref, *, n_coords: int, td: int):
+    """One (Q-tile, D-tile) step: running per-Q-row min SQUARED distance
+    and its global D row.  Validity is a (1, TD) lane row and both outputs
+    are (TQ, 1) sublane columns, as in `hausdorff._min_dist_kernel`."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -26,18 +31,13 @@ def _nn_kernel(q_ref, d_ref, dvalid_ref, dist_ref, idx_ref, *, n_coords: int, td
         dist_ref[...] = jnp.full(dist_ref.shape, BIG, jnp.float32)
         idx_ref[...] = jnp.full(idx_ref.shape, -1, jnp.int32)
 
-    q = q_ref[...]
-    d = d_ref[...]
     # ref.unrolled_sq_dists' exact accumulation (see hausdorff.py) so the
     # kernel stays bitwise equal to the ref oracle across routing changes
-    acc = None
-    for c in range(n_coords):
-        diff = q[:, c][:, None] - d[:, c][None, :]
-        sq = diff * diff
-        acc = sq if acc is None else acc + sq
-    acc = jnp.where(dvalid_ref[...][None, :], acc, BIG)
-    tile_min = jnp.min(acc, axis=1)
-    tile_arg = jnp.argmin(acc, axis=1).astype(jnp.int32) + j * td
+    acc = tile_sq_dists(q_ref[...], d_ref[...], n_coords)
+    acc = jnp.where(dvalid_ref[...], acc, BIG)
+    tile_min = jnp.min(acc, axis=1, keepdims=True)
+    tile_arg = (jnp.argmin(acc, axis=1, keepdims=True).astype(jnp.int32)
+                + j * td)
     better = tile_min < dist_ref[...]
     dist_ref[...] = jnp.where(better, tile_min, dist_ref[...])
     idx_ref[...] = jnp.where(better, tile_arg, idx_ref[...])
@@ -58,21 +58,22 @@ def nn_sq_dists(
     nd = d.shape[0]
     grid = (nq // tq, nd // td)
     kernel = functools.partial(_nn_kernel, n_coords=n_coords, td=td)
-    return pl.pallas_call(
+    dist, idx = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tq, q.shape[1]), lambda i, j: (i, 0)),
             pl.BlockSpec((td, d.shape[1]), lambda i, j: (j, 0)),
-            pl.BlockSpec((td,), lambda i, j: (j,)),
+            pl.BlockSpec((1, td), lambda i, j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
-            pl.BlockSpec((tq,), lambda i, j: (i,)),
+            pl.BlockSpec((tq, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((tq, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq,), jnp.float32),
-            jax.ShapeDtypeStruct((nq,), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(q, d, d_valid)
+    )(q, d, d_valid.reshape(1, nd))
+    return dist[:, 0], idx[:, 0]

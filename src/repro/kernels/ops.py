@@ -9,11 +9,12 @@ the autotuner's :func:`autotune.epoch` plus the engine's executable-cache
 keys guarantee a table update re-routes every subsequent dispatch.
 
 The inner impls handle padding to tile multiples, coordinate-dim padding,
-and the TPU/interpret switch (this container is CPU: kernels run with
-interpret=True, which executes the kernel body via XLA ops — correctness
-path; TPU is the perf target).  ``use_kernel=False`` pins the pure-jnp
-oracle in ref.py; ``use_kernel=True`` forces the kernel at any size (the
-padding helpers round tiny inputs up to one tile).
+and the TPU/interpret switch: on the ``tpu`` backend the kernels compile
+through Mosaic; on ``cpu`` (the test suite runs with ``JAX_PLATFORMS=cpu``)
+they run with interpret=True, which executes the kernel body via XLA ops —
+the correctness path; any other backend is an error.  ``use_kernel=False``
+pins the pure-jnp oracle in ref.py; ``use_kernel=True`` forces the kernel
+at any size (the padding helpers round tiny inputs up to one tile).
 """
 from __future__ import annotations
 
@@ -31,8 +32,20 @@ from repro.kernels import set_intersect as _si
 
 Array = jax.Array
 
-INTERPRET = jax.default_backend() != "tpu"
 BIG = ref.BIG
+
+
+def _interpret() -> bool:
+    """Pallas mode for the default backend: compiled kernels on ``tpu``,
+    the interpreter on ``cpu`` (tests and local runs), and an error
+    anywhere else — no backend falls back to the interpreter silently."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels need the 'tpu' backend (or 'cpu' "
+                       f"in interpret mode), not {backend!r}")
 
 
 def _pad_rows(x: Array, mult: int, fill=0.0) -> Array:
@@ -76,7 +89,7 @@ def _directed_hausdorff(
     dp = _pad_rows(_pad_coords(d, width), td)
     dv = _pad_rows(d_valid, td, fill=False)
     mins = _haus.min_sq_dists(qp, dp, dv, n_coords=n_coords, tq=tq, td=td,
-                              interpret=INTERPRET)
+                              interpret=_interpret())
     nnd = jnp.sqrt(jnp.minimum(mins[: q.shape[0]], BIG))
     nnd = jnp.where(q_valid, nnd, -BIG)
     return jnp.max(nnd)
@@ -107,7 +120,7 @@ def _nn_distance(
     dp = _pad_rows(_pad_coords(d, width), td)
     dv = _pad_rows(d_valid, td, fill=False)
     d2, idx = _nn.nn_sq_dists(qp, dp, dv, n_coords=n_coords, tq=tq, td=td,
-                              interpret=INTERPRET)
+                              interpret=_interpret())
     d2 = d2[: q.shape[0]]
     idx = idx[: q.shape[0]]
     dist = jnp.sqrt(jnp.minimum(d2, BIG))
@@ -165,7 +178,7 @@ def _directed_hausdorff_grid(
         dv = jnp.pad(ds_valid, ((0, 0), (0, 0), (0, -nd % td)))
         mins = _haus.min_sq_dists_grid(qp, dp, dv, n_coords=n_coords,
                                        tq=tq, td=td,
-                                       interpret=INTERPRET)[:, :, :nq]
+                                       interpret=_interpret())[:, :, :nq]
         mins = jnp.minimum(mins, ref.BIG)
     else:
         if nd % tile:
@@ -256,7 +269,7 @@ def _bound_matrices(
     rqp = _pad_rows(rq, tn)
     rdp = _pad_rows(rd, tm)
     lb, ub = _bm.bound_matrices(oqp, rqp, odp, rdp, n_coords=n_coords,
-                                tn=tn, tm=tm, interpret=INTERPRET)
+                                tn=tn, tm=tm, interpret=_interpret())
     return lb[:nq, :nd], ub[:nq, :nd]
 
 
@@ -309,7 +322,7 @@ def _bound_grid(
     dop = _pad_rows(d_ok, ts, fill=False)
     lb, ub = _bm.bound_grid(oqp, rqp, qop, odp, rdp, dop,
                             levels=levels, n_coords=n_coords, tb=tb, ts=ts,
-                            interpret=INTERPRET)
+                            interpret=_interpret())
     return lb[:, :B, :S], ub[:, :B, :S]
 
 
@@ -333,7 +346,7 @@ def _set_intersect_counts(
     na, nb = sa.shape[0], sb.shape[0]
     sap = _pad_rows(sa, ta)
     sbp = _pad_rows(sb, tb)
-    out = _si.intersect_counts(sap, sbp, ta=ta, tb=tb, interpret=INTERPRET)
+    out = _si.intersect_counts(sap, sbp, ta=ta, tb=tb, interpret=_interpret())
     return out[:na, :nb]
 
 
