@@ -6,10 +6,10 @@
 
 One chip: builds a T-Drive-scale repository (the public release's 10,357
 taxi trajectories, 100-1000 2-D points each, 16,384 padded slots) through
-``build_repository``, forces each Pallas kernel at serving widths against
-its jnp oracle, serves a warmed-up burst of mixed traffic (all nine ops
-and the three pipeline kinds) through ``SearchServer`` over a
-``QueryEngine``, and runs a short live lane (ingest / delete / replace
+``build_repository``, forces each Pallas kernel against its jnp oracle
+(widths in ``kernel_phase``), serves a warmed-up burst of mixed traffic
+(all nine ops and the three pipeline kinds) through ``SearchServer`` over
+a ``QueryEngine``, and runs a short live lane (ingest / delete / replace
 interleaved with queries) through ``LiveRepository``.  Sampled answers are
 compared with the plain references: the per-query seed ops,
 ``search.topk_hausdorff_host``, ``join_search.topk_join_host`` and, for the
@@ -17,13 +17,13 @@ live lane's final epoch, ``repo_mutate.build_frozen``.  Ids, masks and
 integer scores must be equal; float values must agree to the jit-vs-eager
 tolerance of the test suite (rtol = atol = 1e-5).
 
-Four chips: one repository (a 256-trajectory T-Drive subset, built
-through ``LiveRepository``'s compiled row builds) and one burst of
-requests through ``ShardedQueryEngine`` on a 4-chip ``data_mesh``
-and ``ReplicatedQueryEngine`` on a 2x2 ``replica_mesh``, each compared with
-the single-device ``QueryEngine``, plus one coalesced ``LiveRepository``
-mutation group on the 4-chip mesh whose repository must equal
-``build_frozen`` bit for bit; per-device residency and placement are
+Four chips: the same 10,357-trajectory lake as one ``LiveRepository`` on
+a 4-chip ``data_mesh``; one coalesced group of mutations, after which the
+mesh repository must equal ``build_frozen`` of its contents bit for bit.
+That oracle repository then serves one 256-request burst through
+``ShardedQueryEngine`` on the 4-chip ``data_mesh`` and
+``ReplicatedQueryEngine`` on a 2x2 ``replica_mesh``, each compared with
+the single-device ``QueryEngine``; per-device residency and placement are
 checked.
 
 Any failed phase raises, so the script exits nonzero; on success the last
@@ -45,22 +45,16 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 TDRIVE_DATASETS = 10_357     # trajectories in the public T-Drive release
 N_REQUESTS = 256             # one burst: ~21 requests of each of 12 kinds
 SAMPLES = 2                  # reference-checked requests per kind
-# The live lane builds every dataset through one batch-of-1 dispatch per
-# stage (core/repo_mutate.init_live), and its oracle does the same again;
-# a 1,024-trajectory T-Drive lake keeps both inside the time limit.
+# The one-chip live lane is cut to the first 1,024 of the 10,357
+# trajectories: it builds every dataset through batch-of-1 stage
+# dispatches (core/repo_mutate.init_live) and its oracle does so again,
+# about 3 minutes more at full size on a v5e.  The full lake's live path
+# runs under --four-chips.
 LIVE_DATASETS = 1024
 MUTATE_EVERY = 8
 N_MUTATIONS = 8
 RTOL = ATOL = 1e-5           # jit-vs-eager tolerance of tests/test_engine.py
 LEAF, THETA = 16, 5
-# --four-chips: four chips cost four times the chip time, and compile
-# time dominates there, so the multi-chip paths run on a T-Drive subset
-# with a shorter burst, built through LiveRepository: its few compiled
-# row-build programs replace the hundreds of small ones that
-# build_repository's op-by-op build compiles
-FOUR_CHIP_DATASETS = 256
-FOUR_CHIP_REQUESTS = 48
-MESH_LIVE_DATASETS = 48          # 64 slots: the group ingests into free slots
 
 
 class SmokeFailure(RuntimeError):
@@ -189,9 +183,14 @@ def build_tdrive(n_datasets: int, compiles: CompileLog):
 
 
 def kernel_phase(compiles: CompileLog) -> None:
-    """Each Pallas entry point forced on at serving widths (1024-point
-    datasets, 2-D points, the 15-node bound grid, theta=5 signatures)
-    against its jnp oracle on the same inputs."""
+    """Each Pallas entry point forced on against its jnp oracle on the same
+    inputs, at the routing-default tiles of ``autotune.DEFAULTS``: point
+    kernels on 1024-point 2-D datasets; the ExactHaus pair grid on 4
+    queries x a 32-candidate chunk of 1024-point datasets; bound matrices
+    256 x 256 nodes; the fused bound grid over the 15 nodes of levels 0..3
+    at 256 queries (its routing threshold) x 2,048 slots, and over the 63
+    nodes of levels 0..5 (rolled-loop reductions) at 8 x 512; signatures
+    of theta=5 (32 words), 256 x 512."""
     import jax.numpy as jnp
     import numpy as np
     from repro.kernels import ops
@@ -201,25 +200,30 @@ def kernel_phase(compiles: CompileLog) -> None:
     pts = lambda *s: jnp.asarray(rng.uniform(0, 100, s).astype(np.float32))
     mask = lambda *s: jnp.asarray(rng.random(s) > 0.1)
     q, d, qv, dv = pts(1024, 2), pts(1024, 2), mask(1024), mask(1024)
-    nodes = 15
-    levels = ((0, 1), (1, 3), (3, 7), (7, 15))
-    grid = (pts(256, nodes, 2), pts(256, nodes), mask(256, nodes),
-            pts(512, nodes, 2), pts(512, nodes), mask(512, nodes))
+    gq, gd = pts(4, 1024, 2), pts(4, 32, 1024, 2)
+    gqv, gdv = mask(4, 1024), mask(4, 32, 1024)
+
+    def bound_tree(b, s, n):
+        return (pts(b, n, 2), pts(b, n), mask(b, n),
+                pts(s, n, 2), pts(s, n), mask(s, n))
+
+    levels = ((0, 1), (1, 3), (3, 7), (7, 15), (15, 31), (31, 63))
+    grid15, grid63 = bound_tree(256, 2048, 15), bound_tree(8, 512, 63)
     sa = jnp.asarray(rng.integers(0, 2**32, (256, 32), dtype=np.uint32))
     sb = jnp.asarray(rng.integers(0, 2**32, (512, 32), dtype=np.uint32))
     cases = {
         "directed_hausdorff": lambda k: ops.directed_hausdorff(
             q, d, qv, dv, use_kernel=k),
         "hausdorff_grid": lambda k: ops.directed_hausdorff_grid(
-            q.reshape(4, 256, 2), jnp.stack([d.reshape(4, 256, 2)] * 2, 1),
-            qv.reshape(4, 256), jnp.stack([dv.reshape(4, 256)] * 2, 1),
-            td=256, use_kernel=k),
+            gq, gd, gqv, gdv, use_kernel=k),
         "nn_distance": lambda k: ops.nn_distance(q, d, qv, dv, use_kernel=k),
         "bound_matrices": lambda k: ops.bound_matrices(
-            grid[0][:, 0], grid[1][:, 0], grid[3][:, 0], grid[4][:, 0],
-            use_kernel=k),
-        "bound_grid": lambda k: ops.bound_grid(*grid, levels=levels,
+            grid15[0][:, 0], grid15[1][:, 0], grid15[3][:256, 0],
+            grid15[4][:256, 0], use_kernel=k),
+        "bound_grid": lambda k: ops.bound_grid(*grid15, levels=levels[:4],
                                                use_kernel=k),
+        "bound_grid:63": lambda k: ops.bound_grid(*grid63, levels=levels,
+                                                  use_kernel=k),
         "set_intersect": lambda k: ops.set_intersect_counts(
             sa, sb, use_kernel=k),
     }
@@ -467,16 +471,15 @@ def compare_results(kind_rows, got, want, m: Matches, what: str):
                 m.exact(kind, x, y, f"{what} {name}")
 
 
-def live_lane(compiles: CompileLog) -> None:
+def live_lane(compiles: CompileLog, lake) -> None:
     from repro.engine import LiveRepository, QueryEngine
     from repro.launch.serve_search import (MUTATION_OPS, SearchServer,
                                            _to_query, make_traffic)
 
     c0 = compiles.snapshot()
     t0 = time.perf_counter()
-    lake = tdrive_lake(LIVE_DATASETS)
     live = LiveRepository(lake, leaf_capacity=LEAF, theta=THETA)
-    log(f"live lane: {LIVE_DATASETS} datasets, {live.n_slots} slots, "
+    log(f"live lane: {len(lake)} datasets, {live.n_slots} slots, "
         f"initialized in {time.perf_counter() - t0:.3f} s")
     traffic = make_traffic(live.repo, lake, MUTATE_EVERY * N_MUTATIONS + 1,
                            seed=3, mutate_every=MUTATE_EVERY)
@@ -540,7 +543,7 @@ def one_chip(compiles: CompileLog, n_datasets: int = TDRIVE_DATASETS,
     m = Matches()
     check_against_references(repo, traffic, answers, m)
     m.report("serve-vs-reference")
-    live_lane(compiles)
+    live_lane(compiles, lake[:LIVE_DATASETS])
 
 
 def placement_check(repo, n_devices: int, label: str) -> None:
@@ -565,8 +568,8 @@ def residency(repo, label: str, whole: int, share: float) -> None:
               f"{label}: {d} holds {b} bytes, expected ~{share} of {whole}")
 
 
-def four_chips(compiles: CompileLog, n_datasets: int = FOUR_CHIP_DATASETS,
-               n_requests: int = FOUR_CHIP_REQUESTS) -> None:
+def four_chips(compiles: CompileLog, n_datasets: int = TDRIVE_DATASETS,
+               n_requests: int = N_REQUESTS) -> None:
     import jax
     from repro.engine import (LiveRepository, QueryEngine, data_mesh,
                               repo_device_bytes)
@@ -576,47 +579,20 @@ def four_chips(compiles: CompileLog, n_datasets: int = FOUR_CHIP_DATASETS,
 
     n_dev = len(jax.devices())
     check(n_dev >= 4, f"--four-chips needs 4 devices, JAX found {n_dev}")
+
+    # the mesh live lane holds the whole lake; one coalesced group of
+    # mutations, then the cold build_frozen oracle of its final contents
     c0 = compiles.snapshot()
     t0 = time.perf_counter()
-    lake = tdrive_lake(n_datasets)
-    repo = LiveRepository(lake, leaf_capacity=LEAF, theta=THETA).repo
-    whole = sum(repo_device_bytes(repo).values())
-    log(f"repository: {n_datasets} datasets, {repo.n_slots} slots, "
-        f"{whole} bytes on one device, built in "
-        f"{time.perf_counter() - t0:.3f} s")
-    compiles.report("build", c0)
-    traffic = make_traffic(repo, lake, n_requests, seed=1)
-    kinds = [kind_of(op, p) for op, p in traffic]
-
-    local = QueryEngine(repo)
-    base = serve_results(local, traffic, compiles, "single-device")
-
-    sharded = ShardedQueryEngine(repo, mesh=data_mesh(4))
-    residency(sharded.repo, "sharded 1x4", whole, 0.25)
-    placement_check(sharded.repo, 4, "sharded 1x4")
-    m = Matches()
-    compare_results(["sharded:" + k for k in kinds],
-                    serve_results(sharded, traffic, compiles, "sharded 1x4"),
-                    base, m, "sharded")
-    m.report("sharded-vs-single")
-
-    replicated = ReplicatedQueryEngine(repo, n_replicas=2, n_data=2)
-    residency(replicated.repo, "replicated 2x2", whole, 0.5)
-    placement_check(replicated.repo, 4, "replicated 2x2")
-    m = Matches()
-    compare_results(["replicated:" + k for k in kinds],
-                    serve_results(replicated, traffic, compiles,
-                                  "replicated 2x2"),
-                    base, m, "replicated")
-    m.report("replicated-vs-single")
-
-    # the mesh live lane is judged on its repository: bitwise equal to a
-    # cold build_frozen, so every answer over it equals the cold engine's
-    c0 = compiles.snapshot()
-    live_set = tdrive_lake(MESH_LIVE_DATASETS)
-    live = LiveRepository(live_set, leaf_capacity=LEAF, theta=THETA,
+    lake = tdrive_lake(n_datasets + 4)
+    lake, extra = lake[:n_datasets], lake[n_datasets:]
+    live = LiveRepository(lake, leaf_capacity=LEAF, theta=THETA,
                           mesh=data_mesh(4))
-    extra = tdrive_lake(MESH_LIVE_DATASETS + 4)[-4:]
+    log(f"mesh live repository: {n_datasets} datasets, {live.n_slots} "
+        f"slots, built in {time.perf_counter() - t0:.3f} s")
+    compiles.report("mesh live build", c0)
+    c0 = compiles.snapshot()
+    t0 = time.perf_counter()
     group = live.prepare_group([("ingest", None, extra[0]),
                                 ("delete", 3, None),
                                 ("replace", 5, extra[1]),
@@ -627,14 +603,47 @@ def four_chips(compiles: CompileLog, n_datasets: int = FOUR_CHIP_DATASETS,
     check(not any(isinstance(o, Exception) for o in outcomes),
           f"mesh live group: {outcomes}")
     log(f"mesh live group: 6 mutations published as epoch {live.epoch} "
-        f"({live.stats.mutations_coalesced} coalesced)")
+        f"({live.stats.mutations_coalesced} coalesced) in "
+        f"{time.perf_counter() - t0:.3f} s")
     check(live.epoch == 1, "one coalesced publish")
-    placement_check(live.repo, 4, "mesh live repository")
+    t0 = time.perf_counter()
+    repo = live.frozen_repository()
+    jax.block_until_ready(repo)
+    log(f"build_frozen oracle: {time.perf_counter() - t0:.3f} s")
+    compiles.report("mesh live group + oracle", c0)
+    whole = sum(repo_device_bytes(repo).values())
+    residency(live.repo, "mesh live 1x4", whole, 0.25)
+    placement_check(live.repo, 4, "mesh live 1x4")
     m = Matches()
-    repos_equal(live.repo, live.frozen_repository(), live.n_slots, m,
-                "mesh-live:repository")
-    compiles.report("mesh live", c0)
+    repos_equal(live.repo, repo, live.n_slots, m, "mesh-live:repository")
     m.report("mesh-live-vs-build_frozen")
+    del live
+
+    # the serving paths, over the oracle's repository (single device)
+    traffic = make_traffic(repo, lake, n_requests, seed=1)
+    kinds = [kind_of(op, p) for op, p in traffic]
+    base = serve_results(QueryEngine(repo), traffic, compiles,
+                         "single-device")
+
+    sharded = ShardedQueryEngine(repo, mesh=data_mesh(4))
+    residency(sharded.repo, "sharded 1x4", whole, 0.25)
+    placement_check(sharded.repo, 4, "sharded 1x4")
+    m = Matches()
+    compare_results(["sharded:" + k for k in kinds],
+                    serve_results(sharded, traffic, compiles, "sharded 1x4"),
+                    base, m, "sharded")
+    m.report("sharded-vs-single")
+    del sharded
+
+    replicated = ReplicatedQueryEngine(repo, n_replicas=2, n_data=2)
+    residency(replicated.repo, "replicated 2x2", whole, 0.5)
+    placement_check(replicated.repo, 4, "replicated 2x2")
+    m = Matches()
+    compare_results(["replicated:" + k for k in kinds],
+                    serve_results(replicated, traffic, compiles,
+                                  "replicated 2x2"),
+                    base, m, "replicated")
+    m.report("replicated-vs-single")
 
 
 def serve_results(engine, traffic, compiles: CompileLog, label: str):

@@ -23,8 +23,8 @@ TM = 256
 # fused (B, S) bound grid: query-batch x corpus-slot tiles
 TB = 8
 TS = 128
-# node ranges up to this size are unrolled in the bound-grid kernel; wider
-# tree levels loop
+# node-range reductions of up to this many steps are unrolled in the
+# bound-grid kernel; longer ones loop
 UNROLL = 16
 
 
@@ -84,16 +84,12 @@ def bound_matrices(
 
 def _fold(a: int, b: int, fn, combine):
     """combine(...combine(fn(a), fn(a + 1))..., fn(b - 1)) over node ids
-    [a, b): statically unrolled for small ranges, a ``fori_loop``
-    otherwise.  Starting from fn(a) (no identity element) keeps the
-    reduction bitwise equal to a jnp.min / jnp.max over the range."""
-    if b - a <= UNROLL:
-        out = fn(a)
-        for i in range(a + 1, b):
-            out = combine(out, fn(i))
-        return out
+    [a, b), as one ``fori_loop``: fully unrolled up to UNROLL steps (Mosaic
+    takes only a full unroll or none).  Starting from fn(a) (no identity
+    element) keeps the reduction bitwise equal to a jnp.min / jnp.max over
+    the range."""
     return jax.lax.fori_loop(a + 1, b, lambda i, acc: combine(acc, fn(i)),
-                             fn(a))
+                             fn(a), unroll=b - a - 1 <= UNROLL)
 
 
 def _bound_grid_kernel(oq_ref, rq_ref, qok_ref, od_ref, rd_ref, dok_ref,

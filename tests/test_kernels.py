@@ -212,6 +212,31 @@ def test_bound_grid_routing_boundary(B, S, bitwise):
             np.testing.assert_allclose(kern, refp, rtol=5e-5, atol=1e-5)
 
 
+# levels wider than bound_matrix.UNROLL nodes run the kernel's reduction
+# as a rolled loop instead of an unrolled one
+WIDE_LEVELS = LEVELS7 + ((7, 15), (15, 31), (31, 63))
+
+
+@pytest.mark.parametrize("N,B,S,bitwise", [(31, 1, 7, True),
+                                           (31, 3, 5, True),
+                                           (63, 1, 7, False)])
+def test_bound_grid_wide_levels(N, B, S, bitwise):
+    """Tree levels past the unroll limit (Query.refine_levels >= 5): the
+    kernel vs its fused jnp oracle — bitwise where XLA's contraction
+    choice coincides for the two program shapes, ~ulp elsewhere."""
+    levels = tuple(lv for lv in WIDE_LEVELS if lv[1] <= N)
+    args = _mk_grid(np.random.default_rng(N + B + S), B, S, N=N)
+    kern = ops.bound_grid(*args, levels=levels, use_kernel=True)
+    refp = ops.bound_grid(*args, levels=levels, use_kernel=False)
+    for k, r in zip(kern, refp):
+        k, r = np.asarray(k), np.asarray(r)
+        assert k.shape == (len(levels), B, S)
+        if bitwise:
+            np.testing.assert_array_equal(k, r)
+        else:
+            np.testing.assert_allclose(k, r, rtol=5e-5, atol=1e-5)
+
+
 def test_bound_grid_threshold_crossing(monkeypatch):
     """At the default (256, 256) threshold the route flips to the kernel;
     just below it stays on the fused oracle.  The default route must be

@@ -8,7 +8,8 @@ Mosaic's (8, 128) rule or XLA's layout, and kernels that overrun the
 to ``COORD_PAD`` lanes, datasets padded to 1024 points (T-Drive's 100-1000
 point trajectories), 16,384 repository slots, drains up to the 256-row
 bucket, the ExactHaus chunk of 32, and the fused bound grid over the 15
-nodes of tree levels 0..3 (``refine_levels=3``).  Tiles are the routing
+nodes of tree levels 0..3 (``refine_levels=3``) and over the wider levels
+a query may ask for (up to 127 nodes, whose reductions loop).  Tiles are the routing
 defaults in ``kernels/autotune.DEFAULTS``.
 
 The topology is described inside a module fixture (never at import), so
@@ -32,7 +33,8 @@ SLOTS = 16384                  # T-Drive's 10,357 datasets, padded
 DRAIN = 256                    # largest engine bucket
 CHUNK = 32                     # QueryEngine.default_chunk
 SIG_WORDS = 32                 # theta=5 z-order signature words
-LEVELS = ((0, 1), (1, 3), (3, 7), (7, 15))   # bound-grid levels 0..3
+# bound-grid levels 0..6; serving refines levels 0..3 (15 nodes)
+LEVELS = ((0, 1), (1, 3), (3, 7), (7, 15), (15, 31), (31, 63), (63, 127))
 
 
 @pytest.fixture(scope="module")
@@ -102,7 +104,8 @@ def test_bound_matrices_compiles(one_chip):
 @pytest.mark.parametrize("max_level", range(len(LEVELS)))
 def test_bound_grid_compiles_in_scoped_vmem(one_chip, max_level):
     """N=15 (max_level 3) once needed 18.7 MiB of scoped VMEM (limit
-    16 MiB)."""
+    16 MiB); levels past 16 nodes (max_level >= 5) reduce in a rolled
+    loop."""
     tb, ts = _tiles("bound_grid")
     levels = LEVELS[:max_level + 1]
     n = levels[-1][1]
