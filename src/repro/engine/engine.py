@@ -70,7 +70,7 @@ from repro.core import join_search, point_search, search
 from repro.core.build import pad_batch
 from repro.core.index import DatasetIndex
 from repro.core.repo_index import Repository
-from repro.engine import batched_ops
+from repro.engine import batched_ops, spans
 from repro.engine import plan as plan_lib
 from repro.kernels import autotune
 from repro.engine.query import Pipeline, Query, SearchResult  # noqa: F401
@@ -613,35 +613,39 @@ class QueryEngine:
         a dispatch).  The common cold case — every row a distinct miss —
         returns the dispatch output UNCHANGED, so a no-repeat workload
         pays only the key digests."""
-        out_rows = [None] * len(keys)
-        miss: list = []
-        hits = 0
-        for i, key in enumerate(keys):
-            row = self._result_cache.get(key)
-            if row is None:
-                miss.append(i)
-            else:
-                self._result_cache.move_to_end(key)
-                out_rows[i] = row
-                hits += 1
-        uniq_pos: dict = {}            # key -> row index in the sub-batch
-        uniq: list = []
-        for i in miss:
-            if keys[i] not in uniq_pos:
-                uniq_pos[keys[i]] = len(uniq)
-                uniq.append(i)
-        self.stats.count_result_cache(
-            op, hits + (len(miss) - len(uniq)), len(uniq))
+        with spans.span("engine.marshal"):
+            out_rows = [None] * len(keys)
+            miss: list = []
+            hits = 0
+            for i, key in enumerate(keys):
+                row = self._result_cache.get(key)
+                if row is None:
+                    miss.append(i)
+                else:
+                    self._result_cache.move_to_end(key)
+                    out_rows[i] = row
+                    hits += 1
+            uniq_pos: dict = {}        # key -> row index in the sub-batch
+            uniq: list = []
+            for i in miss:
+                if keys[i] not in uniq_pos:
+                    uniq_pos[keys[i]] = len(uniq)
+                    uniq.append(i)
+            self.stats.count_result_cache(
+                op, hits + (len(miss) - len(uniq)), len(uniq))
         if not hits and len(uniq) == len(keys):    # all-distinct cold batch
             raw = dispatch(None)
-            self._cache_insert(keys, split(raw))
+            with spans.span("engine.results", part="cache", rows=len(keys)):
+                self._cache_insert(keys, split(raw))
             return raw
-        if uniq:
-            rows = split(dispatch(uniq))
-            self._cache_insert([keys[i] for i in uniq], rows)
-            for i in miss:
-                out_rows[i] = rows[uniq_pos[keys[i]]]
-        return join(out_rows)
+        raw = dispatch(uniq) if uniq else None
+        with spans.span("engine.results", part="cache", rows=len(uniq)):
+            if uniq:
+                rows = split(raw)
+                self._cache_insert([keys[i] for i in uniq], rows)
+                for i in miss:
+                    out_rows[i] = rows[uniq_pos[keys[i]]]
+            return join(out_rows)
 
     # -- query construction ------------------------------------------------
 
@@ -689,7 +693,8 @@ class QueryEngine:
         winning dataset ids to the point stages with the id handoff
         staying on device.  Returns one
         :class:`~repro.engine.query.SearchResult` per input, in INPUT
-        order.
+        order, as a list whose ``groups`` is the number of dispatch groups
+        the call planned (:class:`~repro.engine.plan.Results`).
         """
         return plan_lib.execute(self, queries)
 
@@ -697,14 +702,16 @@ class QueryEngine:
 
     def _exec_range_search(self, r_lo, r_hi):
         """RangeS for B query boxes -> dataset masks (B, B_pad)."""
-        r_lo = jnp.atleast_2d(jnp.asarray(r_lo, jnp.float32))
-        r_hi = jnp.atleast_2d(jnp.asarray(r_hi, jnp.float32))
+        with spans.span("engine.marshal"):
+            r_lo = jnp.atleast_2d(jnp.asarray(r_lo, jnp.float32))
+            r_hi = jnp.atleast_2d(jnp.asarray(r_hi, jnp.float32))
+            if self.result_cache_size:
+                lo_np, hi_np = np.asarray(r_lo), np.asarray(r_hi)
+                keys = [("range_search", self._repo_epoch,
+                         _digest(lo_np[i], hi_np[i]))
+                        for i in range(lo_np.shape[0])]
         if not self.result_cache_size:
             return self._range_search_dispatch(r_lo, r_hi)
-        lo_np, hi_np = np.asarray(r_lo), np.asarray(r_hi)
-        keys = [("range_search", self._repo_epoch,
-                 _digest(lo_np[i], hi_np[i]))
-                for i in range(lo_np.shape[0])]
         return self._serve_cached(
             "range_search", keys,
             lambda sel: self._range_search_dispatch(
@@ -715,22 +722,28 @@ class QueryEngine:
     def _range_search_dispatch(self, r_lo, r_hi):
         B = r_lo.shape[0]
         bucket = self.bucket_for(B)
-        fn, cached = self._executable(
-            ("range_search", bucket), self.dispatch.build_range_search)
-        masks, _ = fn(self._pad_rows(r_lo, bucket),
-                      self._pad_rows(r_hi, bucket))
+        with spans.span("engine.marshal"):
+            fn, cached = self._executable(
+                ("range_search", bucket), self.dispatch.build_range_search)
+            args = (self._pad_rows(r_lo, bucket),
+                    self._pad_rows(r_hi, bucket))
+        with spans.span("engine.launch"):
+            masks, _ = fn(*args)
         self.stats.count("range_search", B, bucket, cached=cached)
         return masks[:B]
 
     def _exec_topk_ia(self, q_lo, q_hi, k: int):
         """Top-k IA for B query boxes -> (vals, ids) each (B, k)."""
-        q_lo = jnp.atleast_2d(jnp.asarray(q_lo, jnp.float32))
-        q_hi = jnp.atleast_2d(jnp.asarray(q_hi, jnp.float32))
+        with spans.span("engine.marshal"):
+            q_lo = jnp.atleast_2d(jnp.asarray(q_lo, jnp.float32))
+            q_hi = jnp.atleast_2d(jnp.asarray(q_hi, jnp.float32))
+            if self.result_cache_size:
+                lo_np, hi_np = np.asarray(q_lo), np.asarray(q_hi)
+                keys = [("topk_ia", self._repo_epoch, k,
+                         _digest(lo_np[i], hi_np[i]))
+                        for i in range(lo_np.shape[0])]
         if not self.result_cache_size:
             return self._topk_ia_dispatch(q_lo, q_hi, k)
-        lo_np, hi_np = np.asarray(q_lo), np.asarray(q_hi)
-        keys = [("topk_ia", self._repo_epoch, k, _digest(lo_np[i], hi_np[i]))
-                for i in range(lo_np.shape[0])]
         return self._serve_cached(
             "topk_ia", keys,
             lambda sel: self._topk_ia_dispatch(
@@ -740,24 +753,29 @@ class QueryEngine:
     def _topk_ia_dispatch(self, q_lo, q_hi, k: int):
         B = q_lo.shape[0]
         bucket = self.bucket_for(B)
-        fn, cached = self._executable(
-            ("topk_ia", bucket, k),
-            lambda: self.dispatch.build_topk_ia(k))
-        vals, ids = fn(self._pad_rows(q_lo, bucket),
-                       self._pad_rows(q_hi, bucket))
+        with spans.span("engine.marshal"):
+            fn, cached = self._executable(
+                ("topk_ia", bucket, k),
+                lambda: self.dispatch.build_topk_ia(k))
+            args = (self._pad_rows(q_lo, bucket),
+                    self._pad_rows(q_hi, bucket))
+        with spans.span("engine.launch"):
+            vals, ids = fn(*args)
         self.stats.count("topk_ia", B, bucket, cached=cached)
         return vals[:B], ids[:B]
 
     def _exec_topk_gbo(self, q_sigs, k: int):
         """Top-k GBO for B query signatures -> (vals, ids) each (B, k)."""
-        q_sigs = jnp.asarray(q_sigs)
-        if q_sigs.ndim == 1:
-            q_sigs = q_sigs[None, :]
+        with spans.span("engine.marshal"):
+            q_sigs = jnp.asarray(q_sigs)
+            if q_sigs.ndim == 1:
+                q_sigs = q_sigs[None, :]
+            if self.result_cache_size:
+                sigs_np = np.asarray(q_sigs)
+                keys = [("topk_gbo", self._repo_epoch, k, _digest(sigs_np[i]))
+                        for i in range(sigs_np.shape[0])]
         if not self.result_cache_size:
             return self._topk_gbo_dispatch(q_sigs, k)
-        sigs_np = np.asarray(q_sigs)
-        keys = [("topk_gbo", self._repo_epoch, k, _digest(sigs_np[i]))
-                for i in range(sigs_np.shape[0])]
         return self._serve_cached(
             "topk_gbo", keys,
             lambda sel: self._topk_gbo_dispatch(_take_rows(q_sigs, sel), k),
@@ -766,10 +784,13 @@ class QueryEngine:
     def _topk_gbo_dispatch(self, q_sigs, k: int):
         B = q_sigs.shape[0]
         bucket = self.bucket_for(B)
-        fn, cached = self._executable(
-            ("topk_gbo", bucket, k),
-            lambda: self.dispatch.build_topk_gbo(k))
-        vals, ids = fn(self._pad_rows(q_sigs, bucket))
+        with spans.span("engine.marshal"):
+            fn, cached = self._executable(
+                ("topk_gbo", bucket, k),
+                lambda: self.dispatch.build_topk_gbo(k))
+            padded = self._pad_rows(q_sigs, bucket)
+        with spans.span("engine.launch"):
+            vals, ids = fn(padded)
         self.stats.count("topk_gbo", B, bucket, cached=cached)
         return vals[:B], ids[:B]
 
@@ -782,13 +803,16 @@ class QueryEngine:
         coarse signatures and the refine reads resident points, so ANY
         published mutation may change a row) — `set_repo_epoch` retires
         them wholesale like every dataset-granularity op."""
-        q_pts = jnp.asarray(q_pts, jnp.float32)
-        q_val = jnp.asarray(q_val, bool)
+        with spans.span("engine.marshal"):
+            q_pts = jnp.asarray(q_pts, jnp.float32)
+            q_val = jnp.asarray(q_val, bool)
+            if self.result_cache_size:
+                pts_np, val_np = np.asarray(q_pts), np.asarray(q_val)
+                keys = [(op, self._repo_epoch, k,
+                         _digest(pts_np[i], val_np[i]))
+                        for i in range(pts_np.shape[0])]
         if not self.result_cache_size:
             return self._topk_join_dispatch(op, q_pts, q_val, k)
-        pts_np, val_np = np.asarray(q_pts), np.asarray(q_val)
-        keys = [(op, self._repo_epoch, k, _digest(pts_np[i], val_np[i]))
-                for i in range(pts_np.shape[0])]
         return self._serve_cached(
             op, keys,
             lambda sel: self._topk_join_dispatch(
@@ -804,13 +828,17 @@ class QueryEngine:
         bucket = self.bucket_for(B)
         chunk = self.default_chunk
         key = (op, bucket, q_pts.shape[1], k, chunk)
-        fn, cached = self._executable(
-            key, lambda: getattr(self.dispatch, "build_" + op)(k, chunk))
-        vals, ids, nodes, cand_after, evaluated = fn(
-            self._pad_rows(q_pts, bucket), self._pad_rows(q_val, bucket))
+        with spans.span("engine.marshal"):
+            fn, cached = self._executable(
+                key, lambda: getattr(self.dispatch, "build_" + op)(k, chunk))
+            args = (self._pad_rows(q_pts, bucket),
+                    self._pad_rows(q_val, bucket))
+        with spans.span("engine.launch"):
+            vals, ids, nodes, cand_after, evaluated = fn(*args)
         self.stats.count(op, B, bucket, cached=cached)
-        stats = join_search.join_stats_host(
-            self._n_valid, evaluated[:B], nodes[:B], cand_after[:B])
+        with spans.span("engine.readback"):
+            stats = join_search.join_stats_host(
+                self._n_valid, evaluated[:B], nodes[:B], cand_after[:B])
         self.stats.record_search(op, stats)
         return vals[:B], ids[:B], stats
 
@@ -825,11 +853,15 @@ class QueryEngine:
         T = ds_ids.shape[0]
         bucket = self.bucket_for(T)
         key = ("join_rerank", mode, bucket, q_pts.shape[1])
-        fn, cached = self._executable(
-            key, lambda: self.dispatch.build_join_rerank(mode))
-        scores = fn(self._pad_rows(jnp.asarray(ds_ids, jnp.int32), bucket),
-                    self._pad_rows(jnp.asarray(q_pts, jnp.float32), bucket),
-                    self._pad_rows(jnp.asarray(q_val, bool), bucket))
+        with spans.span("engine.marshal"):
+            fn, cached = self._executable(
+                key, lambda: self.dispatch.build_join_rerank(mode))
+            args = (
+                self._pad_rows(jnp.asarray(ds_ids, jnp.int32), bucket),
+                self._pad_rows(jnp.asarray(q_pts, jnp.float32), bucket),
+                self._pad_rows(jnp.asarray(q_val, bool), bucket))
+        with spans.span("engine.launch"):
+            scores = fn(*args)
         # stage-2 rows count like the point-stage dispatches do (one row
         # per stage-1 winner), keeping hits+misses == dispatches intact
         self.stats.count(op, T, bucket, cached=cached)
@@ -841,13 +873,16 @@ class QueryEngine:
         eps_eff)."""
         if not self.result_cache_size:
             return self._topk_hausdorff_approx_dispatch(q_batch, k, eps)
-        pts, val = np.asarray(q_batch.points), np.asarray(q_batch.valid)
-        # depth is part of the key: (points, valid, depth) fully determine
-        # a DatasetIndex built by this codebase (node stats are derived
-        # from them), so same points under a different tree never collide
-        keys = [("approx_haus", self._repo_epoch, k, float(eps),
-                 q_batch.depth,
-                 _digest(pts[i], val[i])) for i in range(pts.shape[0])]
+        with spans.span("engine.readback"):
+            pts, val = np.asarray(q_batch.points), np.asarray(q_batch.valid)
+        with spans.span("engine.marshal"):
+            # depth is part of the key: (points, valid, depth) fully
+            # determine a DatasetIndex built by this codebase (node stats
+            # are derived from them), so same points under a different
+            # tree never collide
+            keys = [("approx_haus", self._repo_epoch, k, float(eps),
+                     q_batch.depth,
+                     _digest(pts[i], val[i])) for i in range(pts.shape[0])]
         return self._serve_cached(
             "topk_hausdorff_approx", keys,
             lambda sel: self._topk_hausdorff_approx_dispatch(
@@ -858,10 +893,13 @@ class QueryEngine:
         B = q_batch.points.shape[0]
         bucket = self.bucket_for(B)
         key = ("approx_haus", bucket, q_batch.points.shape[1], k)
-        fn, cached = self._executable(
-            key, lambda: self.dispatch.build_topk_hausdorff_approx(k))
-        padded = self._pad_tree(q_batch, bucket)
-        vals, ids, eps_eff = fn(padded, eps=jnp.float32(eps))
+        with spans.span("engine.marshal"):
+            fn, cached = self._executable(
+                key, lambda: self.dispatch.build_topk_hausdorff_approx(k))
+            padded = self._pad_tree(q_batch, bucket)
+            eps = jnp.float32(eps)
+        with spans.span("engine.launch"):
+            vals, ids, eps_eff = fn(padded, eps=eps)
         self.stats.count("topk_hausdorff_approx", B, bucket, cached=cached)
         return vals[:B], ids[:B], eps_eff[:B]
 
@@ -883,12 +921,14 @@ class QueryEngine:
         if not self.result_cache_size:
             return self._topk_hausdorff_dispatch(
                 q_batch, k, refine_levels, chunk)
-        pts, val = np.asarray(q_batch.points), np.asarray(q_batch.valid)
-        # depth in the key for the same reason as ApproHaus (a
-        # different tree over the same points changes the SearchStats)
-        keys = [("exact_haus", self._repo_epoch, k, refine_levels, chunk,
-                 q_batch.depth,
-                 _digest(pts[i], val[i])) for i in range(pts.shape[0])]
+        with spans.span("engine.readback"):
+            pts, val = np.asarray(q_batch.points), np.asarray(q_batch.valid)
+        with spans.span("engine.marshal"):
+            # depth in the key for the same reason as ApproHaus (a
+            # different tree over the same points changes the SearchStats)
+            keys = [("exact_haus", self._repo_epoch, k, refine_levels,
+                     chunk, q_batch.depth,
+                     _digest(pts[i], val[i])) for i in range(pts.shape[0])]
         return self._serve_cached(
             "topk_hausdorff", keys,
             lambda sel: self._topk_hausdorff_dispatch(
@@ -906,22 +946,26 @@ class QueryEngine:
         bucket = self.bucket_for(B)
         key = ("exact_haus", bucket, q_batch.points.shape[1], k,
                refine_levels, chunk)
-        fn, cached = self._executable(
-            key, lambda: self.dispatch.build_topk_hausdorff(k, refine_levels,
-                                                            chunk))
-        padded = self._pad_tree(q_batch, bucket)
-        vals, ids, nodes, cand_after, evaluated = fn(padded)
+        with spans.span("engine.marshal"):
+            fn, cached = self._executable(
+                key, lambda: self.dispatch.build_topk_hausdorff(
+                    k, refine_levels, chunk))
+            padded = self._pad_tree(q_batch, bucket)
+        with spans.span("engine.launch"):
+            vals, ids, nodes, cand_after, evaluated = fn(padded)
         self.stats.count("topk_hausdorff", B, bucket, cached=cached)
-        nodes = np.asarray(nodes)
-        cand_after = np.asarray(cand_after)
-        evaluated = np.asarray(evaluated)
-        stats = [
-            search.SearchStats(
-                int(nodes[i]), int(cand_after[i]), int(evaluated[i]),
-                1.0 - int(evaluated[i]) / max(self._n_valid, 1),
-            )
-            for i in range(B)
-        ]
+        with spans.span("engine.readback"):
+            nodes = np.asarray(nodes)
+            cand_after = np.asarray(cand_after)
+            evaluated = np.asarray(evaluated)
+        with spans.span("engine.results"):
+            stats = [
+                search.SearchStats(
+                    int(nodes[i]), int(cand_after[i]), int(evaluated[i]),
+                    1.0 - int(evaluated[i]) / max(self._n_valid, 1),
+                )
+                for i in range(B)
+            ]
         self.stats.record_search("topk_hausdorff", stats)
         return vals[:B], ids[:B], stats
 
@@ -939,13 +983,14 @@ class QueryEngine:
         their PointStats; :meth:`EngineStats.record_point_search` books
         only the rows that actually dispatched."""
         if self.result_cache_size and not isinstance(ds_ids, jax.Array):
-            ids_np = np.atleast_1d(np.asarray(ds_ids, np.int32))
-            lo_np = np.atleast_2d(np.asarray(r_lo, np.float32))
-            hi_np = np.atleast_2d(np.asarray(r_hi, np.float32))
-            keys = [("range_points", int(ids_np[i]),
-                     self.slot_epoch(ids_np[i]),
-                     _digest(lo_np[i], hi_np[i]))
-                    for i in range(ids_np.shape[0])]
+            with spans.span("engine.marshal"):
+                ids_np = np.atleast_1d(np.asarray(ds_ids, np.int32))
+                lo_np = np.atleast_2d(np.asarray(r_lo, np.float32))
+                hi_np = np.atleast_2d(np.asarray(r_hi, np.float32))
+                keys = [("range_points", int(ids_np[i]),
+                         self.slot_epoch(ids_np[i]),
+                         _digest(lo_np[i], hi_np[i]))
+                        for i in range(ids_np.shape[0])]
             return self._serve_cached(
                 "range_points", keys,
                 lambda sel: self._range_points_dispatch(
@@ -962,25 +1007,30 @@ class QueryEngine:
         is no longer discarded: per-query leaf pruning stats are computed
         from it (device-side sums, one tiny transfer) and folded into
         ``EngineStats`` via :meth:`EngineStats.record_point_search`."""
-        ds_ids = jnp.atleast_1d(jnp.asarray(ds_ids, jnp.int32))
-        r_lo = jnp.atleast_2d(jnp.asarray(r_lo, jnp.float32))
-        r_hi = jnp.atleast_2d(jnp.asarray(r_hi, jnp.float32))
-        B = ds_ids.shape[0]
-        bucket = self.bucket_for(B)
-        fn, cached = self._executable(
-            ("range_points", bucket), self.dispatch.build_range_points)
-        take, scanned = fn(self._pad_rows(ds_ids, bucket),
-                           self._pad_rows(r_lo, bucket),
-                           self._pad_rows(r_hi, bucket))
+        with spans.span("engine.marshal"):
+            ds_ids = jnp.atleast_1d(jnp.asarray(ds_ids, jnp.int32))
+            r_lo = jnp.atleast_2d(jnp.asarray(r_lo, jnp.float32))
+            r_hi = jnp.atleast_2d(jnp.asarray(r_hi, jnp.float32))
+            B = ds_ids.shape[0]
+            bucket = self.bucket_for(B)
+            fn, cached = self._executable(
+                ("range_points", bucket), self.dispatch.build_range_points)
+            args = (self._pad_rows(ds_ids, bucket),
+                    self._pad_rows(r_lo, bucket),
+                    self._pad_rows(r_hi, bucket))
+        with spans.span("engine.launch"):
+            take, scanned = fn(*args)
         self.stats.count("range_points", B, bucket, cached=cached)
         n_leaves = int(scanned.shape[1])
-        sc = np.asarray(jnp.sum(scanned[:B], axis=1))
-        stats = [
-            point_search.PointStats(
-                n_leaves, int(sc[i]),
-                float(1.0 - int(sc[i]) / max(n_leaves, 1)))
-            for i in range(B)
-        ]
+        with spans.span("engine.readback"):
+            sc = np.asarray(jnp.sum(scanned[:B], axis=1))
+        with spans.span("engine.results"):
+            stats = [
+                point_search.PointStats(
+                    n_leaves, int(sc[i]),
+                    float(1.0 - int(sc[i]) / max(n_leaves, 1)))
+                for i in range(B)
+            ]
         self.stats.record_point_search("range_points", stats)
         return take[:B], stats
 
@@ -993,12 +1043,14 @@ class QueryEngine:
         host-resident; the on-device stage-2 handoff dispatches
         directly."""
         if self.result_cache_size and not isinstance(ds_ids, jax.Array):
-            ids_np = np.atleast_1d(np.asarray(ds_ids, np.int32))
-            pts = np.asarray(q_batch.points)
-            val = np.asarray(q_batch.valid)
-            keys = [("nnp", int(ids_np[i]), self.slot_epoch(ids_np[i]),
-                     q_batch.depth, _digest(pts[i], val[i]))
-                    for i in range(ids_np.shape[0])]
+            with spans.span("engine.readback"):
+                pts = np.asarray(q_batch.points)
+                val = np.asarray(q_batch.valid)
+            with spans.span("engine.marshal"):
+                ids_np = np.atleast_1d(np.asarray(ds_ids, np.int32))
+                keys = [("nnp", int(ids_np[i]), self.slot_epoch(ids_np[i]),
+                         q_batch.depth, _digest(pts[i], val[i]))
+                        for i in range(ids_np.shape[0])]
             return self._serve_cached(
                 "nnp", keys,
                 lambda sel: self._nnp_dispatch(
@@ -1016,23 +1068,28 @@ class QueryEngine:
         on BOTH dispatchers; the surviving ``pair_live`` mask is surfaced
         as per-query PointStats — the same counters the host `nnp_pruned`
         reports — instead of being thrown away."""
-        ds_ids = jnp.atleast_1d(jnp.asarray(ds_ids, jnp.int32))
-        B = ds_ids.shape[0]
-        bucket = self.bucket_for(B)
-        fn, cached = self._executable(
-            ("nnp", bucket, q_batch.points.shape[1]),
-            self.dispatch.build_nnp)
-        dists, idxs, pair_live = fn(self._pad_rows(ds_ids, bucket),
-                                    self._pad_tree(q_batch, bucket))
+        with spans.span("engine.marshal"):
+            ds_ids = jnp.atleast_1d(jnp.asarray(ds_ids, jnp.int32))
+            B = ds_ids.shape[0]
+            bucket = self.bucket_for(B)
+            fn, cached = self._executable(
+                ("nnp", bucket, q_batch.points.shape[1]),
+                self.dispatch.build_nnp)
+            args = (self._pad_rows(ds_ids, bucket),
+                    self._pad_tree(q_batch, bucket))
+        with spans.span("engine.launch"):
+            dists, idxs, pair_live = fn(*args)
         self.stats.count("nnp", B, bucket, cached=cached)
         pairs = int(pair_live.shape[1] * pair_live.shape[2])
-        live = np.asarray(jnp.sum(pair_live[:B], axis=(1, 2)))
-        stats = [
-            point_search.PointStats(
-                pairs, int(live[i]),
-                float(1.0 - int(live[i]) / max(pairs, 1)))
-            for i in range(B)
-        ]
+        with spans.span("engine.readback"):
+            live = np.asarray(jnp.sum(pair_live[:B], axis=(1, 2)))
+        with spans.span("engine.results"):
+            stats = [
+                point_search.PointStats(
+                    pairs, int(live[i]),
+                    float(1.0 - int(live[i]) / max(pairs, 1)))
+                for i in range(B)
+            ]
         self.stats.record_point_search("nnp", stats)
         return dists[:B], idxs[:B], stats
 

@@ -39,7 +39,6 @@ dispatches at batch 64+).
 """
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 
@@ -48,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import index as index_lib
+from repro.engine import spans
 from repro.engine.query import (DATASET_RERANK_OPS, Pipeline, Query,
                                 SearchResult)
 
@@ -65,66 +65,95 @@ class DispatchGroup:
 
 
 def plan(items, leaf_capacity: int = 16) -> list[DispatchGroup]:
-    """Group a mixed batch into stage-1 dispatch groups (first-seen order;
-    a Pipeline contributes its ``dataset_stage`` here)."""
-    groups: "OrderedDict[tuple, DispatchGroup]" = OrderedDict()
-    for pos, item in enumerate(items):
-        q = item.dataset_stage if isinstance(item, Pipeline) else item
-        key = (q.op, q.statics(), q.query_shape_sig(leaf_capacity))
-        g = groups.get(key)
-        if g is None:
-            g = groups[key] = DispatchGroup(q.op, key[1], key[2])
-        g.rows.append(pos)
-        g.queries.append(q)
-    return list(groups.values())
+    """Check a mixed batch and group it into stage-1 dispatch groups
+    (first-seen order; a Pipeline contributes its ``dataset_stage``
+    here)."""
+    with spans.span("engine.plan"):
+        for it in items:
+            if not isinstance(it, (Query, Pipeline)):
+                raise TypeError(
+                    f"search() takes Query/Pipeline items, got {type(it)!r}")
+            # a STANDALONE point query must name its dataset; only a
+            # Pipeline's point stage may leave ds_id None (filled from the
+            # stage-1 winners) — catch it here with a clear message
+            # instead of an opaque asarray failure inside the group
+            # marshalling
+            if (isinstance(it, Query) and it.op in ("range_points", "nnp")
+                    and it.ds_id is None):
+                raise ValueError(
+                    f"Query(op={it.op!r}) requires ds_id outside a Pipeline "
+                    f"point stage")
+        groups: "OrderedDict[tuple, DispatchGroup]" = OrderedDict()
+        for pos, item in enumerate(items):
+            q = item.dataset_stage if isinstance(item, Pipeline) else item
+            key = (q.op, q.statics(), q.query_shape_sig(leaf_capacity))
+            g = groups.get(key)
+            if g is None:
+                g = groups[key] = DispatchGroup(q.op, key[1], key[2])
+            g.rows.append(pos)
+            g.queries.append(q)
+        return list(groups.values())
 
 
-def count_groups(items, leaf_capacity: int = 16) -> int:
-    """Number of dispatch groups `execute` would compile for a batch:
-    stage-1 op groups + distinct pipeline stage-2 groups.  Host-side
-    only — lets observers (the serving front-end) book group counts
-    without racing on the engine's shared counters."""
-    s2 = {_stage2_key(it.point_stage, leaf_capacity)
-          for it in items if isinstance(it, Pipeline)}
-    return len(plan(items, leaf_capacity)) + len(s2)
+class Results(list):
+    """`execute`'s answers in input order, with ``groups``: the dispatch
+    groups the call planned (stage-1 op groups + pipeline stage-2
+    groups), for callers that book them without reading the engine's
+    shared counters."""
+    groups = 0
 
 
-def execute(engine, items) -> list:
+def execute(engine, items) -> Results:
     """Run a mixed batch through the engine; one SearchResult per input."""
     items = list(items)
-    for it in items:
-        if not isinstance(it, (Query, Pipeline)):
-            raise TypeError(
-                f"search() takes Query/Pipeline items, got {type(it)!r}")
-        # a STANDALONE point query must name its dataset; only a
-        # Pipeline's point stage may leave ds_id None (filled from the
-        # stage-1 winners) — catch it here with a clear message instead
-        # of an opaque asarray failure inside the group marshalling
-        if (isinstance(it, Query) and it.op in ("range_points", "nnp")
-                and it.ds_id is None):
-            raise ValueError(
-                f"Query(op={it.op!r}) requires ds_id outside a Pipeline "
-                f"point stage")
-    results: list = [None] * len(items)
-    stage1: dict = {}          # input pos -> stage-1 SearchResult
-    handoffs: dict = {}        # input pos -> device (k,) winner-id row
-    for g in plan(items, engine.leaf_capacity):
-        # subgroups: replica row-blocks this group's rows span (1 unless
-        # the dispatcher splits rows across replica groups)
-        engine.stats.count_group(g.op, engine._plan_subgroups(len(g.rows)))
-        t0 = time.perf_counter()
-        rows, ids_dev = _run_group(engine, g)
-        engine.stats.record_latency(g.op, time.perf_counter() - t0)
-        for j, (pos, res) in enumerate(zip(g.rows, rows)):
-            if isinstance(items[pos], Pipeline):
-                stage1[pos] = res
-                handoffs[pos] = ids_dev[j]      # device slice: the handoff
-            else:
-                results[pos] = res
-    if stage1:
-        engine.stats.pipeline_stage1 += len(stage1)
-        _run_stage2(engine, items, stage1, handoffs, results)
+    with spans.span("engine.execute", requests=len(items)) as call:
+        results = Results([None] * len(items))
+        stage1: dict = {}          # input pos -> stage-1 SearchResult
+        handoffs: dict = {}        # input pos -> device (k,) winner-id row
+        groups = plan(items, engine.leaf_capacity)
+        for g in groups:
+            # subgroups: replica row-blocks this group's rows span (1
+            # unless the dispatcher splits rows across replica groups)
+            engine.stats.count_group(g.op,
+                                     engine._plan_subgroups(len(g.rows)))
+            with spans.timed("engine.group", op=g.op, stage=1,
+                             rows=len(g.rows)) as gs:
+                before = _group_counters(engine) if gs.live else None
+                rows, ids_dev = _run_group(engine, g)
+                if gs.live:
+                    gs.set(**_group_attrs(engine, before, len(g.rows)))
+            engine.stats.record_latency(g.op, gs.seconds)
+            with spans.span("engine.results", part="handoff") as rs:
+                n = 0
+                for j, (pos, res) in enumerate(zip(g.rows, rows)):
+                    if isinstance(items[pos], Pipeline):
+                        stage1[pos] = res
+                        handoffs[pos] = ids_dev[j]       # device slice
+                        n += 1
+                    else:
+                        results[pos] = res
+                rs.set(rows=n)
+        results.groups = len(groups)
+        if stage1:
+            engine.stats.pipeline_stage1 += len(stage1)
+            results.groups += _run_stage2(engine, items, stage1, handoffs,
+                                          results)
+        call.set(groups=results.groups)
     return results
+
+
+def _group_counters(engine) -> tuple:
+    return engine.stats.cache_misses, engine.stats.result_cache_hits
+
+
+def _group_attrs(engine, before, rows: int) -> dict:
+    """A recorded group's ``bucket`` (of the rows it dispatched, 0 when
+    the result cache answered them all), ``cached`` (no program built)
+    and ``result_hits`` (rows the result cache answered)."""
+    misses, hits = _group_counters(engine)
+    hits -= before[1]
+    return {"bucket": engine.bucket_for(rows - hits) if rows > hits else 0,
+            "cached": misses == before[0], "result_hits": hits}
 
 
 # ---------------------------------------------------------------------------
@@ -139,11 +168,11 @@ def _stack_boxes(queries, attr):
                      for q in queries])
 
 
-def _split(x) -> list:
-    """Materialize a dispatch output once and split it into free numpy
-    row views."""
-    a = np.asarray(x)
-    return [a[i] for i in range(a.shape[0])]
+def _host(*xs) -> list:
+    """Host copies of a group's device outputs (iterating one gives its
+    rows as free views): the host waits here for the group's program."""
+    with spans.span("engine.readback"):
+        return [np.asarray(x) for x in xs]
 
 
 def _group_q_batch(engine, queries):
@@ -166,57 +195,74 @@ def _run_group(engine, g: DispatchGroup):
     pipeline's stage-2 handoff can slice it without the ids ever visiting
     the host."""
     op, qs = g.op, g.queries
-    n = len(qs)
     if op == "range_search":
-        masks = engine._exec_range_search(
-            _stack_boxes(qs, "r_lo"), _stack_boxes(qs, "r_hi"))
-        return [SearchResult(op=op, mask=m) for m in _split(masks)], None
+        with spans.span("engine.marshal"):
+            lo, hi = _stack_boxes(qs, "r_lo"), _stack_boxes(qs, "r_hi")
+        masks, = _host(engine._exec_range_search(lo, hi))
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, mask=m) for m in masks], None
     if op == "topk_ia":
-        vals, ids = engine._exec_topk_ia(
-            _stack_boxes(qs, "r_lo"), _stack_boxes(qs, "r_hi"), qs[0].k)
-        return [SearchResult(op=op, vals=v, ids=i)
-                for v, i in zip(_split(vals), _split(ids))], ids
+        with spans.span("engine.marshal"):
+            lo, hi = _stack_boxes(qs, "r_lo"), _stack_boxes(qs, "r_hi")
+        vals, ids = engine._exec_topk_ia(lo, hi, qs[0].k)
+        v, i = _host(vals, ids)
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, vals=a, ids=b)
+                    for a, b in zip(v, i)], ids
     if op == "topk_gbo":
-        sigs = np.stack([np.asarray(q.q_sig) for q in qs])
+        with spans.span("engine.marshal"):
+            sigs = np.stack([np.asarray(q.q_sig) for q in qs])
         vals, ids = engine._exec_topk_gbo(sigs, qs[0].k)
-        return [SearchResult(op=op, vals=v, ids=i)
-                for v, i in zip(_split(vals), _split(ids))], ids
+        v, i = _host(vals, ids)
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, vals=a, ids=b)
+                    for a, b in zip(v, i)], ids
     if op == "topk_hausdorff_approx":
-        q_batch = _group_q_batch(engine, qs)
+        with spans.span("engine.marshal"):
+            q_batch = _group_q_batch(engine, qs)
         vals, ids, eps_eff = engine._exec_topk_hausdorff_approx(
             q_batch, qs[0].k, qs[0].eps)
-        return [SearchResult(op=op, vals=v, ids=i, extras={"eps_eff": e})
-                for v, i, e in zip(_split(vals), _split(ids),
-                                   _split(eps_eff))], ids
+        v, i, e = _host(vals, ids, eps_eff)
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, vals=a, ids=b, extras={"eps_eff": c})
+                    for a, b, c in zip(v, i, e)], ids
     if op == "topk_hausdorff":
-        q_batch = _group_q_batch(engine, qs)
+        with spans.span("engine.marshal"):
+            q_batch = _group_q_batch(engine, qs)
         vals, ids, stats = engine._exec_topk_hausdorff(
             q_batch, qs[0].k, qs[0].refine_levels, qs[0].chunk)
-        return [SearchResult(op=op, vals=v, ids=i, stats=s)
-                for v, i, s in zip(_split(vals), _split(ids),
-                                   stats)], ids
+        v, i = _host(vals, ids)
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, vals=a, ids=b, stats=s)
+                    for a, b, s in zip(v, i, stats)], ids
     if op == "range_points":
-        ds = np.asarray([q.ds_id for q in qs], np.int32)
-        take, stats = engine._exec_range_points(
-            ds, _stack_boxes(qs, "r_lo"), _stack_boxes(qs, "r_hi"))
-        return [SearchResult(op=op, mask=m, stats=s)
-                for m, s in zip(_split(take), stats)], None
+        with spans.span("engine.marshal"):
+            ds = np.asarray([q.ds_id for q in qs], np.int32)
+            lo, hi = _stack_boxes(qs, "r_lo"), _stack_boxes(qs, "r_hi")
+        take, stats = engine._exec_range_points(ds, lo, hi)
+        take, = _host(take)
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, mask=m, stats=s)
+                    for m, s in zip(take, stats)], None
     if op == "nnp":
-        ds = np.asarray([q.ds_id for q in qs], np.int32)
-        q_batch = _group_q_batch(engine, qs)
+        with spans.span("engine.marshal"):
+            ds = np.asarray([q.ds_id for q in qs], np.int32)
+            q_batch = _group_q_batch(engine, qs)
         dists, idxs, stats = engine._exec_nnp(ds, q_batch)
-        valid = _split(q_batch.valid)
-        return [SearchResult(op=op, vals=d, ids=i, mask=m, stats=s)
-                for d, i, m, s in zip(_split(dists), _split(idxs),
-                                      valid, stats)], None
+        d, i, valid = _host(dists, idxs, q_batch.valid)
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, vals=a, ids=b, mask=m, stats=s)
+                    for a, b, m, s in zip(d, i, valid, stats)], None
     if op in DATASET_RERANK_OPS:
-        pts, val = _stack_pointsets(
-            [q.q for q in qs],
-            max(q.built_capacity(engine.leaf_capacity) for q in qs))
+        with spans.span("engine.marshal"):
+            pts, val = _stack_pointsets(
+                [q.q for q in qs],
+                max(q.built_capacity(engine.leaf_capacity) for q in qs))
         vals, ids, stats = engine._exec_topk_join(op, pts, val, qs[0].k)
-        return [SearchResult(op=op, vals=v, ids=i, stats=s)
-                for v, i, s in zip(_split(vals), _split(ids),
-                                   stats)], ids
+        v, i = _host(vals, ids)
+        with spans.span("engine.results"):
+            return [SearchResult(op=op, vals=a, ids=b, stats=s)
+                    for a, b, s in zip(v, i, stats)], ids
     raise ValueError(f"unplannable op {op!r}")  # pragma: no cover
 
 
@@ -258,18 +304,35 @@ def _stage2_key(ps: Query, leaf_capacity: int) -> tuple:
     return (ps.op, ps.statics())
 
 
-def _run_stage2(engine, items, stage1, handoffs, results) -> None:
-    groups: "OrderedDict[tuple, list[int]]" = OrderedDict()
-    for pos in stage1:
-        groups.setdefault(
-            _stage2_key(items[pos].point_stage, engine.leaf_capacity),
-            []).append(pos)
+def _run_stage2(engine, items, stage1, handoffs, results) -> int:
+    """Run every pipeline's point stage, grouped; returns the number of
+    stage-2 groups."""
+    with spans.span("engine.plan"):
+        groups: "OrderedDict[tuple, list[int]]" = OrderedDict()
+        for pos in stage1:
+            groups.setdefault(
+                _stage2_key(items[pos].point_stage, engine.leaf_capacity),
+                []).append(pos)
     for key, poss in groups.items():
         pop = key[0]
         ks = [items[pos].dataset_stage.k for pos in poss]
         total = int(sum(ks))
         engine.stats.count_group(pop, engine._plan_subgroups(total))
-        t0 = time.perf_counter()
+        with spans.timed("engine.group", op=pop, stage=2, rows=total) as gs:
+            before = _group_counters(engine) if gs.live else None
+            _run_stage2_group(engine, items, stage1, handoffs, results, key,
+                              poss, ks)
+            if gs.live:
+                gs.set(**_group_attrs(engine, before, total))
+        engine.stats.record_latency(pop, gs.seconds)
+        engine.stats.pipeline_stage2 += len(poss)
+    return len(groups)
+
+
+def _run_stage2_group(engine, items, stage1, handoffs, results, key, poss,
+                      ks) -> None:
+    pop, total = key[0], int(sum(ks))
+    with spans.span("engine.marshal"):
         # winner ids, handed off ON DEVICE (sliced from the stage-1
         # dispatch output): -1 sentinels (k past the valid dataset count)
         # are clamped to slot 0 for the gather and masked out below.
@@ -278,11 +341,11 @@ def _run_stage2(engine, items, stage1, handoffs, results) -> None:
         w_flat = jnp.concatenate([handoffs[pos] for pos in poss])
         valid_flat = w_flat >= 0
         ds_flat = jnp.where(valid_flat, w_flat, 0).astype(jnp.int32)
-        offs = np.concatenate([[0], np.cumsum(ks)])
-        valid_np = np.asarray(valid_flat)
-        valid_rows = [valid_np[offs[i]:offs[i + 1]]
-                      for i in range(len(poss))]
-        if pop == "range_points":
+    valid_np, = _host(valid_flat)
+    offs = np.concatenate([[0], np.cumsum(ks)])
+    valid_rows = [valid_np[offs[i]:offs[i + 1]] for i in range(len(poss))]
+    if pop == "range_points":
+        with spans.span("engine.marshal"):
             def _tile_box(pos, k, attr):
                 b = np.asarray(getattr(items[pos].point_stage, attr),
                                np.float32)
@@ -292,8 +355,9 @@ def _run_stage2(engine, items, stage1, handoffs, results) -> None:
                                  for pos, k in zip(poss, ks)])
             hi = np.concatenate([_tile_box(pos, k, "r_hi")
                                  for pos, k in zip(poss, ks)])
-            take, stats = engine._exec_range_points(ds_flat, lo, hi)
-            take_np = np.asarray(take)
+        take, stats = engine._exec_range_points(ds_flat, lo, hi)
+        take_np, = _host(take)
+        with spans.span("engine.results"):
             off = 0
             for pos, k, v in zip(poss, ks, valid_rows):
                 results[pos] = SearchResult(
@@ -303,13 +367,14 @@ def _run_stage2(engine, items, stage1, handoffs, results) -> None:
                     extras={"stage1": stage1[pos],
                             "ds_ids": stage1[pos].ids, "valid": v})
                 off += k
-        elif pop in DATASET_RERANK_OPS:
-            # dataset→dataset: exact join score of each winner slot vs the
-            # pipeline's query set (one grouped dispatch, ids on device),
-            # then a host-side re-rank to the stage's top-k.  Sentinel
-            # winners were clamped to slot 0 above; their rows are forced
-            # to score -1 here, so a pipeline with ZERO surviving winners
-            # degrades to all-sentinel output instead of ranking slot 0
+    elif pop in DATASET_RERANK_OPS:
+        # dataset→dataset: exact join score of each winner slot vs the
+        # pipeline's query set (one grouped dispatch, ids on device), then
+        # a host-side re-rank to the stage's top-k.  Sentinel winners were
+        # clamped to slot 0 above; their rows are forced to score -1 here,
+        # so a pipeline with ZERO surviving winners degrades to
+        # all-sentinel output instead of ranking slot 0
+        with spans.span("engine.marshal"):
             pts, val = _stack_pointsets(
                 [items[pos].point_stage.q for pos in poss], key[2])
             reps = np.asarray(ks, np.int32)
@@ -317,8 +382,9 @@ def _run_stage2(engine, items, stage1, handoffs, results) -> None:
                                  total_repeat_length=total)
             val_rep = jnp.repeat(jnp.asarray(val), reps, axis=0,
                                  total_repeat_length=total)
-            scores = engine._exec_join_rerank(pop, ds_flat, pts_rep, val_rep)
-            s_np = np.asarray(scores)
+        scores = engine._exec_join_rerank(pop, ds_flat, pts_rep, val_rep)
+        s_np, = _host(scores)
+        with spans.span("engine.results"):
             off = 0
             for pos, k, v in zip(poss, ks, valid_rows):
                 k2 = items[pos].point_stage.k
@@ -336,15 +402,16 @@ def _run_stage2(engine, items, stage1, handoffs, results) -> None:
                     extras={"stage1": stage1[pos],
                             "ds_ids": stage1[pos].ids, "valid": v})
                 off += k
-        else:  # nnp
+    else:  # nnp
+        with spans.span("engine.marshal"):
             rows = _stage2_nnp_rows(engine, items, poss)
             reps = np.asarray(ks, np.int32)
             q_flat = jax.tree.map(
                 lambda x: jnp.repeat(x, reps, axis=0,
                                      total_repeat_length=total), rows)
-            dists, idxs, stats = engine._exec_nnp(ds_flat, q_flat)
-            d_np, i_np = np.asarray(dists), np.asarray(idxs)
-            qv_np = np.asarray(q_flat.valid)
+        dists, idxs, stats = engine._exec_nnp(ds_flat, q_flat)
+        d_np, i_np, qv_np = _host(dists, idxs, q_flat.valid)
+        with spans.span("engine.results"):
             off = 0
             for pos, k, v in zip(poss, ks, valid_rows):
                 results[pos] = SearchResult(
@@ -356,8 +423,6 @@ def _run_stage2(engine, items, stage1, handoffs, results) -> None:
                     extras={"stage1": stage1[pos],
                             "ds_ids": stage1[pos].ids, "valid": v})
                 off += k
-        engine.stats.record_latency(pop, time.perf_counter() - t0)
-        engine.stats.pipeline_stage2 += len(poss)
 
 
 def _stage2_nnp_rows(engine, items, poss):

@@ -62,7 +62,7 @@ import jax
 import numpy as np
 
 from repro.core.repo_index import Repository
-from repro.engine import Pipeline, Query, QueryEngine, SearchResult
+from repro.engine import Pipeline, Query, QueryEngine, SearchResult, spans
 
 # ops the submit() shim knows how to wrap into a Query/Pipeline; the
 # engine's planner handles the grouping, so ANY mix of these may share one
@@ -160,15 +160,9 @@ class ServerStats:
     batch_size_sum: int = 0
     latency_sum: float = 0.0
     latencies: list = field(default_factory=list)   # per-request seconds
-    op_ewma: dict = field(default_factory=dict)     # op -> EWMA latency s
     mutations: int = 0                      # mutation-lane ops applied
     mutation_latency_sum: float = 0.0
     mutation_latencies: list = field(default_factory=list)
-
-    #: same smoothing as EngineStats.EWMA_ALPHA — both feeds estimate
-    #: "how long does one more batch of this op take" for the adaptive
-    #: straggler window
-    EWMA_ALPHA = 0.2
 
     @property
     def mean_batch(self) -> float:
@@ -178,14 +172,11 @@ class ServerStats:
     def mean_latency_ms(self) -> float:
         return 1e3 * self.latency_sum / max(self.requests, 1)
 
-    def record(self, op: str, seconds: float) -> None:
+    def record(self, seconds: float) -> None:
         """Book one answered request's submit->result latency."""
         self.requests += 1
         self.latency_sum += seconds
         self.latencies.append(seconds)
-        prev = self.op_ewma.get(op)
-        self.op_ewma[op] = (seconds if prev is None
-                            else prev + self.EWMA_ALPHA * (seconds - prev))
 
     def record_mutation(self, seconds: float) -> None:
         """Book one applied mutation's submit->publish latency (kept out
@@ -399,7 +390,10 @@ class SearchServer:
         one query segment plus one tail run of mutations, so the
         per-segment planning/dispatch floor is paid once per drain —
         splitting a segment in two costs ~a full extra group floor,
-        which under churn was most of the serving collapse."""
+        which under churn was most of the serving collapse.
+
+        The batch forming, from the first item taken, is the
+        ``server.drain`` span, which starts the drain's id."""
         if self._carry is not None:
             first = self._carry
             self._carry = None
@@ -410,7 +404,20 @@ class SearchServer:
                 return []
             if first is None:
                 return []
-        batch = [first]
+        spans.next_drain()
+        with spans.span("server.drain") as sp:
+            t0 = self.clock() if sp.live else 0.0
+            batch = self._fill([first])
+            if sp.live:
+                waits = [max(0.0, t0 - r.t_submit) for r in batch
+                         if isinstance(r, Request)]
+                sp.set(requests=len(waits),
+                       wait_sum_ms=1e3 * sum(waits),
+                       wait_max_ms=1e3 * max(waits, default=0.0))
+        return batch
+
+    def _fill(self, batch: list) -> list:
+        """:meth:`_drain` past its first item."""
         if self.adaptive:
             # depth-scaled bound: when the backlog already exceeds
             # max_batch, per-drain overhead (planning plus one engine
@@ -536,40 +543,37 @@ class SearchServer:
         """One declarative engine call for a (sub-)drain of queries: the
         planner groups compatible rows into shared dispatches and
         returns per-request results in input order."""
-        from repro.engine import plan as plan_lib
-
+        # dispatch groups as the planner counted them for THIS call
+        # (stage-1 op groups + pipeline stage-2 groups; the engine's
+        # shared counters would also count a client calling it from
+        # another thread); a stand-in engine's plain list books one
         try:
             results = self.engine.search([r.query for r in segment])
+            groups = getattr(results, "groups", 1)
         except Exception:
             # a poisoned row fails the whole mixed call; isolate by
             # re-running per request so every healthy future still
             # resolves and only the bad rows carry the exception
             # (the executable cache makes the re-runs cheap)
-            results = []
+            results, groups = [], 0
             for r in segment:
                 try:
-                    results.append(self.engine.search([r.query])[0])
+                    out = self.engine.search([r.query])
+                    groups += getattr(out, "groups", 1)
+                    results.append(out[0])
                 except Exception as e:
                     results.append(e)
-        now = self.clock()
-        # dispatch-group count (stage-1 op groups + pipeline stage-2
-        # groups), planned locally (host-only grouping) so a client
-        # sharing the engine from another thread can't skew the
-        # server's own metric; guarded — the accounting must never be
-        # able to kill the dispatcher after results exist
-        try:
-            self.stats.batches += plan_lib.count_groups(
-                [r.query for r in segment], self.engine.leaf_capacity)
-        except Exception:
-            self.stats.batches += 1
-        self.stats.batch_size_sum += len(segment)
-        for req, res in zip(segment, results):
-            self.stats.record(req.op, now - req.t_submit)
-            if isinstance(res, Exception):
-                if not req.future.done():
-                    req.future.set_exception(res)
-            else:
-                req.future.set_result(_legacy_result(res))
+        with spans.span("server.deliver"):
+            now = self.clock()
+            self.stats.batches += groups
+            self.stats.batch_size_sum += len(segment)
+            for req, res in zip(segment, results):
+                self.stats.record(now - req.t_submit)
+                if isinstance(res, Exception):
+                    if not req.future.done():
+                        req.future.set_exception(res)
+                else:
+                    req.future.set_result(_legacy_result(res))
 
     def _loop(self) -> None:
         while self._running:

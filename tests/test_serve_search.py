@@ -163,9 +163,8 @@ def test_queue_timeout_flush(env):
 
 def test_adaptive_drain_and_latency_stats(env):
     """The adaptive (queue-depth-driven) policy must answer every request
-    correctly, book per-op latency EWMAs on both the server (request
-    latency) and the engine (dispatch latency — what sizes the straggler
-    window), and expose latency percentiles."""
+    correctly, book the engine's per-op dispatch latency EWMA (what sizes
+    the straggler window), and expose latency percentiles."""
     datasets, repo = env
     engine = QueryEngine(repo)
     server = SearchServer(engine, max_batch=16, max_wait_ms=100.0,
@@ -183,7 +182,6 @@ def test_adaptive_drain_and_latency_stats(env):
             np.testing.assert_array_equal(np.asarray(res),
                                           np.asarray(want))
         assert server.stats.requests == 6
-        assert server.stats.op_ewma["range_search"] > 0.0
         assert server.stats.p99_ms >= server.stats.p50_ms >= 0.0
         assert engine.stats.latency_ewma["range_search"] > 0.0
         # a lone straggler after the EWMAs exist exercises the sized
